@@ -20,9 +20,10 @@ Invariants (DESIGN.md §15):
     run that did not publish appears anywhere on the chain — except
     snapshots re-legitimized by a quarantine release, which must be
     covered by a recorded re-verified release head.
-5.  **Lost-ack crashes are still atomic.** A crashed run whose commit
-    IS on the chain (died after merge, before acknowledging) is held
-    to the committed-run rules; one that is not is held to invisible.
+5.  **Lost-ack crashes are still atomic.** A crashed or failed run
+    whose commit IS on the chain (died or raised after merge, before
+    acknowledging) is held to the committed-run rules; one that is not
+    is held to invisible.
 6.  **No mystery publications.** Every chain commit carrying a run_id
     belongs to a known record.
 7.  **The Fig. 4 guardrail held.** No unverified quarantine merge
@@ -97,7 +98,7 @@ def check_history(catalog: Catalog, records: Sequence, *,
         known_runs.add(rid)
         on_chain = by_run.get(rid, [])
         published = r.outcome == "committed" or (
-            r.outcome == "crashed" and on_chain)     # lost-ack
+            r.outcome in ("crashed", "failed") and on_chain)  # lost-ack
         if r.outcome == "committed" and not on_chain:
             v.append(f"{rid}: committed but no commit on {target!r}")
             continue

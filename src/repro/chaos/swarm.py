@@ -77,7 +77,7 @@ class AgentRecord:
     run_id: str
     intent: str                       # behavior drawn for this run
     outcome: str = "pending"          # committed|aborted|abandoned|crashed
-                                      # |released|skipped|branch_lost
+                                      # |failed|released|skipped|branch_lost
     tables: dict[str, str] = dataclasses.field(default_factory=dict)
     branch: str | None = None
     final_commit: str | None = None
@@ -196,6 +196,12 @@ def run_swarm(config: SwarmConfig, *,
                 _do_run(rec, rng, agent, k, intent)
         except InjectedCrash as e:
             rec.outcome = "crashed"
+            rec.error = str(e)
+        except InjectedFault as e:
+            # an ordinary error at a seam outside the abort path (after
+            # begin's branch, before or after the merge CAS): the run
+            # was never acknowledged, like a crash.
+            rec.outcome = "failed"
             rec.error = str(e)
         except TransactionAborted as e:
             rec.outcome = "aborted"

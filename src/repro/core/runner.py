@@ -155,8 +155,10 @@ class Client:
         the ref, with a nearest-name suggestion. The compiled logical
         tree flows through the standard pipeline: ``plan()`` with
         row-count stats, ``optimize()`` (``optimizer_passes=()`` skips
-        optimization; ``None`` = the default passes), the stats-driven
-        ``auto`` backend, and the content-addressed :class:`NodeCache`
+        optimization; ``None`` = the default passes), the active
+        execution backend (:func:`repro.exec.active_backend` —
+        ``vectorized`` unless one is selected), and the content-addressed
+        :class:`NodeCache`
         — so re-running any spelling of the same query at the same
         commit executes zero nodes. Reads are snapshot-isolated against
         the resolved commit; nothing is committed.

@@ -380,12 +380,6 @@ class TransactionalRun:
                         self.branch, into=self.target, run_id=self.run_id,
                         message=f"txn commit {self.run_id}",
                         expected_head=self._target_head, _system=True)
-                    # chaos: published but not yet acknowledged — a
-                    # crash here is the lost-ack window: the commit is
-                    # on the target, the TXN branch is orphaned, the
-                    # registry still says "running". Recovery = GC.
-                    fault_point("txn.commit.post_merge",
-                                run_id=self.run_id, commit=merged.id)
                     if att_span is not None:
                         att_span.set(outcome="published",
                                      commit=merged.id)
@@ -463,6 +457,13 @@ class TransactionalRun:
                     raise TransactionAborted(
                         f"publication failed: {e}", branch=self.branch,
                         cause=e) from e
+        # chaos: published but not yet acknowledged — the lost-ack
+        # window: the commit is on the target, the TXN branch is
+        # orphaned, the registry still says "running". Past the CAS
+        # nothing may abort the run (its state is public), so an error
+        # here propagates like a crash. Recovery = GC.
+        fault_point("txn.commit.post_merge",
+                    run_id=self.run_id, commit=merged.id)
         self._status = "committed"
         self.final_commit = merged
         if not self.keep_branch_on_success:

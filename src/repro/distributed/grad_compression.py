@@ -24,8 +24,6 @@ import jax
 import jax.numpy as jnp
 from jax.sharding import Mesh, PartitionSpec as P
 
-from repro.distributed.sharding import shard_map
-
 
 def quantize_int8(x: jax.Array, block: int = 256
                   ) -> tuple[jax.Array, jax.Array]:
@@ -88,8 +86,8 @@ def compressed_psum_pod(grads: Any, mesh: Mesh, *,
         return tuple(x for pair in outs for x in pair)
 
     specs = tuple(P() for _ in flat)
-    out = shard_map(mapped, mesh=mesh, in_specs=specs * 2,
-                    out_specs=specs * 2, check_vma=False)(
+    out = jax.shard_map(mapped, mesh=mesh, in_specs=specs * 2,
+                        out_specs=specs * 2, check_vma=False)(
         *flat, *err_flat)
     red = jax.tree.unflatten(treedef, list(out[0::2]))
     new_err = jax.tree.unflatten(treedef, list(out[1::2]))

@@ -18,8 +18,6 @@ import jax
 import jax.numpy as jnp
 from jax.sharding import Mesh, PartitionSpec as P
 
-from repro.distributed.sharding import shard_map
-
 
 def pipeline_forward(stage_fn: Callable[[Any, jax.Array], jax.Array],
                      stage_params: Any, x: jax.Array, *, mesh: Mesh,
@@ -76,7 +74,7 @@ def pipeline_forward(stage_fn: Callable[[Any, jax.Array], jax.Array],
 
     other = tuple(a for a in mesh.axis_names if a != "pipe")
     pspec = jax.tree.map(lambda _: P("pipe"), stage_params)
-    return shard_map(
+    return jax.shard_map(
         body, mesh=mesh,
         in_specs=(pspec, P()), out_specs=P(),
         check_vma=False,

@@ -28,25 +28,9 @@ from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 __all__ = ["AxisRules", "use_rules", "lshard", "logical_spec",
            "named_sharding", "TRAIN_RULES", "DECODE_RULES", "FSDP_RULES",
-           "current_rules", "shard_map"]
+           "current_rules"]
 
 AxisEntry = str | tuple[str, ...] | None
-
-
-def shard_map(f, *, mesh, in_specs, out_specs, check_vma: bool = True):
-    """Version-portable ``shard_map``.
-
-    ``jax.shard_map`` (with ``check_vma``) only exists on newer jax;
-    older releases ship it as ``jax.experimental.shard_map.shard_map``
-    with the flag spelled ``check_rep``. All in-repo callers go through
-    this wrapper so the distributed stack runs on both.
-    """
-    if hasattr(jax, "shard_map"):
-        return jax.shard_map(f, mesh=mesh, in_specs=in_specs,
-                             out_specs=out_specs, check_vma=check_vma)
-    from jax.experimental.shard_map import shard_map as _shard_map
-    return _shard_map(f, mesh=mesh, in_specs=in_specs,
-                      out_specs=out_specs, check_rep=check_vma)
 
 
 @dataclasses.dataclass(frozen=True)

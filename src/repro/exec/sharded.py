@@ -94,7 +94,6 @@ import numpy as np
 import jax
 import jax.numpy as jnp
 
-from repro.distributed.sharding import shard_map
 from repro.exec.base import (AggSpec, Columns, _column_length, fill_value,
                              normalize_agg_specs, payload_validity)
 from repro.exec.jax_backend import JaxBackend
@@ -299,8 +298,8 @@ def _probe_fn(ndev: int, cap_l: int, cap_r: int, span_shard: int,
     out = P("shard", None)
     fn = body_masked if masked else body
     in_specs = (spec,) * (3 if masked else 2)
-    mapped = shard_map(fn, mesh=mesh, in_specs=in_specs,
-                       out_specs=(out, out, out), check_vma=False)
+    mapped = jax.shard_map(fn, mesh=mesh, in_specs=in_specs,
+                           out_specs=(out, out, out), check_vma=False)
     shard = NamedSharding(mesh, spec)
     return jax.jit(mapped, in_shardings=(shard,) * len(in_specs))
 
@@ -436,8 +435,8 @@ def _partial_agg_fn(ndev: int, seg_shard: int, col_sig: tuple,
 
     spec = P("shard", None)
     n_in = 1 + 2 * len(col_sig)
-    mapped = shard_map(body, mesh=mesh, in_specs=(spec,) * n_in,
-                       out_specs=spec, check_vma=False)
+    mapped = jax.shard_map(body, mesh=mesh, in_specs=(spec,) * n_in,
+                           out_specs=spec, check_vma=False)
     shard = NamedSharding(mesh, spec)
     return jax.jit(mapped, in_shardings=(shard,) * n_in)
 
@@ -595,17 +594,22 @@ class ShardedBackend(JaxBackend):
         if rec.enabled:
             # every slab in `args` crosses the mesh through all_to_all
             bytes_moved = sum(a.nbytes for a in args)
+            # valid rows each owner shard probes / builds (slab padding
+            # and unmatchable rows excluded)
             kernel_ctx = rec.span(
                 "kernel", op="sharded.exchange_probe", ndev=ndev,
                 mode=("table" if span_shard > 0 else "hash"),
                 fused_mask=fused, all_to_all_bytes=bytes_moved,
-                rows_left=n_left, rows_right=n_right)
+                rows_left=n_left, rows_right=n_right,
+                rows_left_per_shard=(l_idx >= 0).sum(axis=(0, 2)).tolist(),
+                rows_right_per_shard=(r_idx >= 0).sum(
+                    axis=(0, 2)).tolist())
             rec.metrics.histogram(
                 "sharded.all_to_all_bytes").observe(bytes_moved)
         # the packed/wide probes carry int64 intermediates; the x64
         # scope is thread-local and only governs types traced inside.
         with kernel_ctx:
-            with jax.experimental.enable_x64():
+            with jax.enable_x64(True):
                 out = fn(*args)
         starts, counts, gidx = (np.asarray(o) for o in out)
 
@@ -854,13 +858,15 @@ class ShardedBackend(JaxBackend):
                 for dt, stats in col_sig)
             kernel_ctx = rec.span(
                 "kernel", op="sharded.partial_agg", ndev=ndev,
-                rows=n, slots=n_slots, all_to_all_bytes=bytes_moved)
+                rows=n, slots=n_slots, all_to_all_bytes=bytes_moved,
+                rows_per_shard=[min(chunk, max(0, n - d * chunk))
+                                for d in range(ndev)])
             rec.metrics.histogram(
                 "sharded.all_to_all_bytes").observe(bytes_moved)
         # the packed strategy sorts int64-packed lanes; the x64 scope
         # is thread-local and only governs types traced inside.
         with kernel_ctx:
-            with jax.experimental.enable_x64():
+            with jax.enable_x64(True):
                 outs = [np.asarray(o).reshape(-1) for o in
                         fn(gid_slab, *col_slabs)]
 

@@ -5,24 +5,34 @@ VMEM; ``ref.build_probe_table`` documents the canonical sorted-side
 construction, and ``exec.sharded.probe_table`` builds the equivalent
 arrival-order variant inline under ``shard_map`` — for a block of
 probe lanes at a time. TPU Pallas has no vector gather from VMEM, so
-the lookup is realized the same way the segment-sum kernel scatters:
-tile the table over the minor grid dimension and one-hot-reduce each
-table tile against the probe lanes' target slots. A lane's slot falls
-in exactly one tile (the hash is perfect over dense codes — see
-ref.py), so summing the masked contributions across table tiles IS
-the gather.
+the lookup is a one-hot matmul on the MXU: tile the table over the
+minor grid dimension, build the (table slot × probe lane) one-hot of
+each probe row against the tile's slots, and multiply the tile's
+values through it. A lane's slot falls in exactly one tile (the hash
+is perfect over dense codes — see ref.py), so summing the products
+across table tiles IS the gather.
+
+Exactness: the table's int32 (start, count) values are split into
+their four bytes, eight rows of integers in [0, 255] — exact in bf16,
+like the 0/1 one-hot — that the MXU multiplies in one bf16 pass and
+accumulates in f32 without rounding (each output lane receives at most
+one nonzero byte); the wrapper reassembles the bytes, so the result is
+bit-identical to ``ref.hash_probe_ref`` (negative starts included).
 
 Tiling: grid = (n_probe_tiles, n_table_tiles), table minor
 (sequential), so each probe tile's output block is revisited across
-table steps and carries the accumulated (start, count) — the same
-carried-accumulator pattern as the segment-sum kernel. All inputs are
-reshaped to 2D (TPU-friendly; 1D iota is illegal on TPU — the guide's
-broadcasted_iota rule). Invalid lanes (slot outside [0, table_size):
-NULL/NaN keys, other shards' ranges, padding) match no tile and emit
-count 0 — the masked probe.
+table steps and carries the accumulated bytes. Probe lanes are laid
+out lane-dense as (rows, 128) and read in (block_n // 128, 128)
+blocks; the byte table is (8, t_pad) read in (8, block_t) blocks; the
+output is (rows, 8, 128). Every block's last two dims are multiples of
+(8, 128) or the whole array, and the body needs no lane-to-column
+relayout: the table-slot iota runs down the sublanes and each probe
+row is broadcast across them. Invalid lanes (slot outside
+[0, table_size): NULL/NaN keys, other shards' ranges, padding) match
+no slot and emit count 0 — the masked probe.
 
-VMEM at (block_n=256, block_t=512), int32: slots 1KB + table slabs
-2·2KB + one-hot int32 512KB + out 2·1KB ≈ 0.52MB « 16MB.
+VMEM at (block_n=1024, block_t=512): slot/mask blocks 2·2·4KB + byte
+table 2·8KB + out 2·32KB + one-hot temporaries ≈ 0.5MB.
 """
 from __future__ import annotations
 
@@ -32,167 +42,124 @@ import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
+from repro.kernels.segment_sum.kernel import (LANES, lane_rows,
+                                              pallas_call_32, round_up,
+                                              row_layout)
 
-def _probe_body(slot_ref, ts_ref, tc_ref, start_ref, cnt_ref, *,
-                block_n: int, block_t: int):
+_BYTE_ROWS = 8          # 4 start bytes + 4 count bytes
+
+
+def _byte_table(table_start, table_count, t_pad: int):
+    """(8, t_pad) bf16: the little-endian bytes of start then count."""
+    def pad(x):
+        return jnp.pad(x.astype(jnp.int32), (0, t_pad - x.shape[0]))
+
+    words = jax.lax.bitcast_convert_type(
+        jnp.stack([pad(table_start), pad(table_count)]), jnp.uint32)
+    shifts = jnp.arange(4, dtype=jnp.uint32) * jnp.uint32(8)
+    byts = (words[:, None, :] >> shifts[None, :, None]) & jnp.uint32(0xFF)
+    return byts.reshape(_BYTE_ROWS, t_pad).astype(jnp.bfloat16)
+
+
+def _from_bytes(acc, n: int):
+    """(rows, 8, 128) f32 byte accumulators -> (starts, counts) int32."""
+    byts = acc.astype(jnp.uint32).transpose(1, 0, 2).reshape(
+        2, 4, -1)[:, :, :n]
+    shifts = jnp.arange(4, dtype=jnp.uint32) * jnp.uint32(8)
+    # the bytes occupy disjoint bits, so their sum is their OR
+    words = jnp.sum(byts << shifts[None, :, None], axis=1,
+                    dtype=jnp.uint32)
+    out = jax.lax.bitcast_convert_type(words, jnp.int32)
+    return out[0], out[1]
+
+
+def _probe_body(slot_ref, *refs, rows: int, block_t: int, masked: bool):
+    if masked:
+        mask_ref, tab_ref, out_ref = refs
+    else:
+        tab_ref, out_ref = refs
     ti = pl.program_id(1)
 
     @pl.when(ti == 0)
     def _init():
-        start_ref[...] = jnp.zeros_like(start_ref)
-        cnt_ref[...] = jnp.zeros_like(cnt_ref)
+        out_ref[...] = jnp.zeros_like(out_ref)
 
-    slots = slot_ref[0, :]                   # (block_n,)
-    local = slots - ti * block_t             # slot within this table tile
-    # one-hot lookup mask: probe lane i reads table column j iff its
-    # slot lands on j in this tile. 2D iota per the TPU guide.
-    col = jax.lax.broadcasted_iota(jnp.int32, (block_n, block_t), 1)
-    onehot = ((col == local[:, None])
-              & (local >= 0)[:, None]
-              & (local < block_t)[:, None])
-    zero = jnp.zeros((), jnp.int32)
-    # dtype pinned: under an ambient jax_enable_x64 scope jnp.sum
-    # would otherwise accumulate int64 and fail the int32 ref store.
-    start_ref[0, :] += jnp.sum(
-        jnp.where(onehot, ts_ref[0, :][None, :], zero), axis=1,
-        dtype=jnp.int32)
-    cnt_ref[0, :] += jnp.sum(
-        jnp.where(onehot, tc_ref[0, :][None, :], zero), axis=1,
-        dtype=jnp.int32)
+    # table slot per one-hot sublane (2D iota: TPU rule)
+    slot = (jax.lax.broadcasted_iota(jnp.int32, (block_t, LANES), 0)
+            + ti * block_t)
+    tab = tab_ref[...]                        # (8, block_t)
+    one = jnp.ones((), jnp.float32)
+    zero = jnp.zeros((), jnp.float32)
+    for r in range(rows):
+        # probe lane i reads table slot j iff its slot is j (and, for
+        # the filter-fused variant, its mask is set — a masked lane
+        # never leaves VMEM).
+        hit = slot == slot_ref[r:r + 1, :]
+        if masked:
+            hit = hit & (mask_ref[r:r + 1, :] != 0)
+        onehot = jnp.where(hit, one, zero).astype(jnp.bfloat16)
+        out_ref[r] += jnp.dot(tab, onehot,
+                              preferred_element_type=jnp.float32)
 
 
-def _masked_probe_body(slot_ref, mask_ref, ts_ref, tc_ref, start_ref,
-                       cnt_ref, *, block_n: int, block_t: int):
-    """Filter-fused variant: a lane whose mask is 0 matches no table
-    column, so its (start, count) stays at the zero-init — the filtered
-    row never leaves VMEM (no host-side mask application, no
-    intermediate filtered copy)."""
-    ti = pl.program_id(1)
+def probe_tiling(n: int, t: int, block_n: int,
+                 block_t: int) -> tuple[int, int, int, int]:
+    """(rows per block, padded rows, table tile, padded slots) of the
+    probe kernels for n probe lanes over t table slots; the grid is
+    (rows // rb, t_pad // block_t)."""
+    rb, rows = row_layout(n, block_n)
+    block_t = round_up(max(1, min(block_t, t)), LANES)
+    return rb, rows, block_t, round_up(max(t, 1), block_t)
 
-    @pl.when(ti == 0)
-    def _init():
-        start_ref[...] = jnp.zeros_like(start_ref)
-        cnt_ref[...] = jnp.zeros_like(cnt_ref)
 
-    slots = slot_ref[0, :]
-    keep = mask_ref[0, :] != 0
-    local = slots - ti * block_t
-    col = jax.lax.broadcasted_iota(jnp.int32, (block_n, block_t), 1)
-    onehot = ((col == local[:, None])
-              & (local >= 0)[:, None]
-              & (local < block_t)[:, None]
-              & keep[:, None])
-    zero = jnp.zeros((), jnp.int32)
-    start_ref[0, :] += jnp.sum(
-        jnp.where(onehot, ts_ref[0, :][None, :], zero), axis=1,
-        dtype=jnp.int32)
-    cnt_ref[0, :] += jnp.sum(
-        jnp.where(onehot, tc_ref[0, :][None, :], zero), axis=1,
-        dtype=jnp.int32)
+def _probe(table_start, table_count, probe_slots, probe_mask, *,
+           block_n: int, block_t: int, interpret: bool):
+    n = probe_slots.shape[0]
+    rb, rows, block_t, t_pad = probe_tiling(n, table_start.shape[0],
+                                            block_n, block_t)
+    args = [lane_rows(probe_slots.astype(jnp.int32), rows, fill=-1)]
+    if probe_mask is not None:
+        args.append(lane_rows(probe_mask.astype(jnp.int32), rows))
+    args.append(_byte_table(table_start, table_count, t_pad))
+    row_spec = pl.BlockSpec((rb, LANES), lambda p, ti: (p, 0))
+    body = functools.partial(_probe_body, rows=rb, block_t=block_t,
+                             masked=probe_mask is not None)
+    acc = pallas_call_32(
+        body,
+        grid=(rows // rb, t_pad // block_t),
+        in_specs=[row_spec] * (len(args) - 1) + [
+            pl.BlockSpec((_BYTE_ROWS, block_t), lambda p, ti: (0, ti))],
+        out_specs=pl.BlockSpec((rb, _BYTE_ROWS, LANES),
+                               lambda p, ti: (p, 0, 0)),
+        out_shape=jax.ShapeDtypeStruct((rows, _BYTE_ROWS, LANES),
+                                       jnp.float32),
+        interpret=interpret,
+    )(*args)
+    return _from_bytes(acc, n)
 
 
 def hash_probe_kernel(table_start, table_count, probe_slots, *,
-                      block_n: int = 256, block_t: int = 512,
-                      interpret: bool = True):
+                      block_n: int = 1024, block_t: int = 512,
+                      interpret: bool):
     """probe_slots: (n,) int32; table_start/table_count: (T,) int32.
 
-    Pads n to a block_n multiple (padding lanes get slot -1, i.e.
-    masked) and T to a block_t multiple (empty slots carry count 0).
-    Returns (starts (n,) int32, counts (n,) int32) — bit-identical to
-    ``ref.hash_probe_ref``.
+    Pads n to whole (8·128)-lane blocks (padding lanes get slot -1,
+    i.e. masked) and T to a block_t multiple (empty slots carry count
+    0). Returns (starts (n,) int32, counts (n,) int32) — bit-identical
+    to ``ref.hash_probe_ref``.
     """
-    n = probe_slots.shape[0]
-    t = table_start.shape[0]
-    block_n = max(1, min(block_n, n)) if n else 1
-    block_t = max(1, min(block_t, t)) if t else 1
-    pad_n = (-n) % block_n if n else block_n
-    if pad_n:
-        probe_slots = jnp.pad(probe_slots, (0, pad_n),
-                              constant_values=-1)
-    pad_t = (-t) % block_t if t else block_t
-    if pad_t:
-        table_start = jnp.pad(table_start, (0, pad_t))
-        table_count = jnp.pad(table_count, (0, pad_t))
-    n_probe_tiles = probe_slots.shape[0] // block_n
-    n_table_tiles = table_start.shape[0] // block_t
-
-    s2 = probe_slots.astype(jnp.int32).reshape(n_probe_tiles, block_n)
-    ts2 = table_start.astype(jnp.int32).reshape(n_table_tiles, block_t)
-    tc2 = table_count.astype(jnp.int32).reshape(n_table_tiles, block_t)
-
-    body = functools.partial(_probe_body, block_n=block_n,
-                             block_t=block_t)
-    starts, counts = pl.pallas_call(
-        body,
-        grid=(n_probe_tiles, n_table_tiles),
-        in_specs=[
-            pl.BlockSpec((1, block_n), lambda p, ti: (p, 0)),
-            pl.BlockSpec((1, block_t), lambda p, ti: (ti, 0)),
-            pl.BlockSpec((1, block_t), lambda p, ti: (ti, 0)),
-        ],
-        out_specs=[
-            pl.BlockSpec((1, block_n), lambda p, ti: (p, 0)),
-            pl.BlockSpec((1, block_n), lambda p, ti: (p, 0)),
-        ],
-        out_shape=[
-            jax.ShapeDtypeStruct((n_probe_tiles, block_n), jnp.int32),
-            jax.ShapeDtypeStruct((n_probe_tiles, block_n), jnp.int32),
-        ],
-        interpret=interpret,
-    )(s2, ts2, tc2)
-    return starts.reshape(-1)[:n], counts.reshape(-1)[:n]
+    return _probe(table_start, table_count, probe_slots, None,
+                  block_n=block_n, block_t=block_t, interpret=interpret)
 
 
 def masked_hash_probe_kernel(table_start, table_count, probe_slots,
-                             probe_mask, *, block_n: int = 256,
-                             block_t: int = 512,
-                             interpret: bool = True):
+                             probe_mask, *, block_n: int = 1024,
+                             block_t: int = 512, interpret: bool):
     """Filter-fused probe: lanes with ``probe_mask == 0`` emit (0, 0).
 
     Same tiling/padding contract as :func:`hash_probe_kernel` (padding
     lanes get mask 0 as well as slot -1 — doubly dead). Bit-identical
     to ``ref.masked_hash_probe_ref``.
     """
-    n = probe_slots.shape[0]
-    t = table_start.shape[0]
-    block_n = max(1, min(block_n, n)) if n else 1
-    block_t = max(1, min(block_t, t)) if t else 1
-    pad_n = (-n) % block_n if n else block_n
-    if pad_n:
-        probe_slots = jnp.pad(probe_slots, (0, pad_n),
-                              constant_values=-1)
-        probe_mask = jnp.pad(probe_mask.astype(jnp.int32), (0, pad_n))
-    pad_t = (-t) % block_t if t else block_t
-    if pad_t:
-        table_start = jnp.pad(table_start, (0, pad_t))
-        table_count = jnp.pad(table_count, (0, pad_t))
-    n_probe_tiles = probe_slots.shape[0] // block_n
-    n_table_tiles = table_start.shape[0] // block_t
-
-    s2 = probe_slots.astype(jnp.int32).reshape(n_probe_tiles, block_n)
-    m2 = probe_mask.astype(jnp.int32).reshape(n_probe_tiles, block_n)
-    ts2 = table_start.astype(jnp.int32).reshape(n_table_tiles, block_t)
-    tc2 = table_count.astype(jnp.int32).reshape(n_table_tiles, block_t)
-
-    body = functools.partial(_masked_probe_body, block_n=block_n,
-                             block_t=block_t)
-    starts, counts = pl.pallas_call(
-        body,
-        grid=(n_probe_tiles, n_table_tiles),
-        in_specs=[
-            pl.BlockSpec((1, block_n), lambda p, ti: (p, 0)),
-            pl.BlockSpec((1, block_n), lambda p, ti: (p, 0)),
-            pl.BlockSpec((1, block_t), lambda p, ti: (ti, 0)),
-            pl.BlockSpec((1, block_t), lambda p, ti: (ti, 0)),
-        ],
-        out_specs=[
-            pl.BlockSpec((1, block_n), lambda p, ti: (p, 0)),
-            pl.BlockSpec((1, block_n), lambda p, ti: (p, 0)),
-        ],
-        out_shape=[
-            jax.ShapeDtypeStruct((n_probe_tiles, block_n), jnp.int32),
-            jax.ShapeDtypeStruct((n_probe_tiles, block_n), jnp.int32),
-        ],
-        interpret=interpret,
-    )(s2, m2, ts2, tc2)
-    return starts.reshape(-1)[:n], counts.reshape(-1)[:n]
+    return _probe(table_start, table_count, probe_slots, probe_mask,
+                  block_n=block_n, block_t=block_t, interpret=interpret)

@@ -2,8 +2,8 @@
 
 Mirrors ``kernels/segment_sum/ops.py``: ``use_pallas=False`` (default)
 lowers the probe to the XLA gather oracle (``ref.hash_probe_ref``);
-``use_pallas=True`` runs the tiled one-hot kernel (``interpret=True``
-on CPU containers — TPU is the compile target). Both are jit-friendly
+``use_pallas=True`` runs the tiled one-hot kernel (``interpret`` has no
+default: the caller decides it from the platform). Both are jit-friendly
 and are what ``exec.sharded`` calls *inside* its ``shard_map`` body, so
 the per-shard probe inner loop runs on the device that owns the shard.
 
@@ -86,8 +86,8 @@ def _jitted(use_pallas: bool, block_n: int, block_t: int,
 
 
 def hash_probe(table_start, table_count, probe_slots, *,
-               use_pallas: bool = False, block_n: int = 256,
-               block_t: int = 512, interpret: bool = True):
+               use_pallas: bool = False, block_n: int = 1024,
+               block_t: int = 512, interpret: bool):
     """Per-probe-lane (start, count) into the slot-sorted build array.
 
     Accepts jax arrays (traced or concrete) or numpy arrays; numpy
@@ -125,8 +125,8 @@ def _jitted_masked(use_pallas: bool, block_n: int, block_t: int,
 
 def masked_hash_probe(table_start, table_count, probe_slots,
                       probe_mask, *, use_pallas: bool = False,
-                      block_n: int = 256, block_t: int = 512,
-                      interpret: bool = True):
+                      block_n: int = 1024, block_t: int = 512,
+                      interpret: bool):
     """Filter-fused probe: :func:`hash_probe` with a per-lane keep
     mask; masked-out lanes emit (0, 0). Same dispatch ladder (XLA
     oracle / Pallas kernel / numpy floor)."""

@@ -1,4 +1,9 @@
-"""Jit'd public wrappers: XLA segment ops or the Pallas kernels."""
+"""Jit'd public wrappers: XLA segment ops or the Pallas kernels.
+
+The wrappers hold the one routing rule between them: the Pallas kernels
+take values of at most 32 bits, so 64-bit values (x64 on) take the XLA
+segment ops even when ``use_pallas`` is set.
+"""
 from __future__ import annotations
 
 import functools
@@ -11,20 +16,26 @@ from repro.kernels.segment_sum.ref import (masked_segment_reduce_ref,
                                            masked_segment_sum_ref)
 
 
+def _pallas(use_pallas: bool, values) -> bool:
+    return use_pallas and values.dtype.itemsize <= 4
+
+
 @functools.partial(jax.jit, static_argnames=(
     "num_segments", "use_pallas", "block_n", "block_s", "interpret"))
 def masked_segment_sum(values, segment_ids, valid, num_segments: int, *,
                        use_pallas: bool = False,
                        block_n: int = 1024, block_s: int = 512,
-                       interpret: bool = True):
+                       interpret: bool):
     """Per-segment SUM over valid lanes + valid-lane counts.
 
     ``use_pallas=False`` (default) lowers to XLA's scatter-add
     (``jax.ops.segment_sum``); ``use_pallas=True`` runs the tiled
-    Pallas kernel (``interpret=True`` on CPU containers — TPU is the
-    compile target). Both return (sums values.dtype, counts int32).
+    Pallas kernel for values of at most 32 bits. ``interpret`` has
+    no default: the caller decides it from the platform (the execution
+    backends do, in ``exec.jax_backend``). Both return (sums
+    values.dtype, counts int32).
     """
-    if not use_pallas:
+    if not _pallas(use_pallas, values):
         return masked_segment_sum_ref(values, segment_ids, valid,
                                       num_segments)
     return masked_segment_sum_kernel(
@@ -38,7 +49,7 @@ def masked_segment_sum(values, segment_ids, valid, num_segments: int, *,
 def masked_segment_reduce(values, segment_ids, valid, num_segments: int,
                           *, op: str, use_pallas: bool = False,
                           block_n: int = 1024, block_s: int = 512,
-                          interpret: bool = True):
+                          interpret: bool):
     """Per-segment MIN/MAX over valid lanes + valid-lane counts.
 
     ``op`` is ``"min"`` or ``"max"``; NaN in a valid float lane poisons
@@ -47,7 +58,7 @@ def masked_segment_reduce(values, segment_ids, valid, num_segments: int,
     """
     if op not in ("min", "max"):
         raise ValueError(f"unknown segment reduce op: {op!r}")
-    if not use_pallas:
+    if not _pallas(use_pallas, values):
         return masked_segment_reduce_ref(values, segment_ids, valid,
                                          num_segments, op)
     return masked_segment_reduce_kernel(

@@ -253,3 +253,34 @@ def test_checker_accepts_lost_ack_crash_as_published():
                 tables={"a": "a@r0", "c": "c@r0"})
     assert any("partial publication" in v
                for v in check_history(cat, [rec2]))
+
+
+def test_post_merge_failure_never_aborts_a_published_run():
+    """An ordinary error after the merge CAS lands in the lost-ack
+    window: the run's state is public, so it must not be marked
+    aborted (its branch would become reusable). The swarm records it as
+    ``failed``, and the checker holds it to committed-run rules."""
+    from repro.chaos import InjectedFault
+
+    cat = Catalog()
+    reg = RunRegistry()
+    txn = TransactionalRun(cat, "main", run_id="r0", registry=reg)
+    txn.begin()
+    txn.write_tables({"a": "a@r0"})
+    plan = FaultPlan(0, (FaultRule("txn.commit.post_merge",
+                                   "fail", 1.0),))
+    with fault_injection(plan):
+        with pytest.raises(InjectedFault):
+            txn.commit()
+    assert cat.tables("main")["a"] == "a@r0"
+    assert reg.get_run("r0").status == "running"
+    assert cat.branch_info(txn.branch).visibility is Visibility.TXN
+    rec = _rec(run_id="r0", outcome="failed", tables={"a": "a@r0"},
+               branch=txn.branch)
+    assert check_history(cat, [rec]) == []
+    res = run_swarm(SwarmConfig(
+        n_agents=2, runs_per_agent=1, seed=0, hot_tables=1,
+        p_contended=0.0, p_multi=0.0, p_violate=0.0, p_abandon=0.0,
+        p_reuse=0.0, gc_every=0, fault_rules=(FaultRule(
+            "txn.commit.post_merge", "fail", 0.25),)))
+    assert check_swarm(res) == []

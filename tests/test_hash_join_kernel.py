@@ -13,7 +13,8 @@ import pytest
 jax = pytest.importorskip("jax")
 import jax.numpy as jnp  # noqa: E402
 
-from repro.kernels.hash_join.kernel import hash_probe_kernel  # noqa: E402
+from repro.kernels.hash_join.kernel import (  # noqa: E402
+    hash_probe_kernel, probe_tiling)
 from repro.kernels.hash_join.ops import (  # noqa: E402
     build_probe_table_np, hash_probe, hash_probe_np)
 from repro.kernels.hash_join.ref import (  # noqa: E402
@@ -46,6 +47,7 @@ def _oracle(slots_sorted, probes, table_size):
     (3, 5, 2),           # smaller than any block
     (0, 7, 4),           # empty build side
     (100, 0, 16),        # empty probe side
+    (3000, 5000, 1000),  # 5 x 8 kernel tiles: crosses table tiles
 ])
 def test_build_and_probe_match_brute_force(n_build, n_probe,
                                            table_size):
@@ -83,12 +85,25 @@ def test_invalid_build_slots_are_dropped():
     assert c.tolist() == [2, 1, 0, 0]
 
 
+def _assert_distinct_multi_tile_grids(n, t, tilings):
+    """The tilings compared must really differ, each with >= 2 tiles on
+    both grid axes (probe rows, table slots)."""
+    grids = set()
+    for block_n, block_t in tilings:
+        rb, rows, bt, t_pad = probe_tiling(n, t, block_n, block_t)
+        grids.add((rows // rb, t_pad // bt))
+    assert len(grids) == len(tilings), grids
+    assert min(min(g) for g in grids) >= 2, grids
+
+
 def test_kernel_block_shape_invariance():
     """Tiling is a perf knob: output must not depend on block sizes."""
-    slots, probes = _case(777, 1234, 123, seed=3)
-    ts, tc = build_probe_table_np(slots, 123)
+    slots, probes = _case(3000, 5000, 1000, seed=3)
+    ts, tc = build_probe_table_np(slots, 1000)
+    tilings = ((1024, 128), (2048, 256), (3072, 512))
+    _assert_distinct_multi_tile_grids(len(probes), 1000, tilings)
     outs = []
-    for block_n, block_t in ((32, 8), (256, 64), (1024, 512)):
+    for block_n, block_t in tilings:
         s, c = hash_probe_kernel(
             jnp.asarray(ts), jnp.asarray(tc), jnp.asarray(probes),
             block_n=block_n, block_t=block_t, interpret=True)
@@ -102,7 +117,7 @@ def test_ops_wrapper_dispatches_pallas_and_ref():
     slots, probes = _case(300, 700, 50, seed=4)
     ts, tc = build_probe_table_np(slots, 50)
     a = hash_probe(jnp.asarray(ts), jnp.asarray(tc),
-                   jnp.asarray(probes), use_pallas=False)
+                   jnp.asarray(probes), use_pallas=False, interpret=True)
     b = hash_probe(jnp.asarray(ts), jnp.asarray(tc),
                    jnp.asarray(probes), use_pallas=True,
                    block_n=128, block_t=32, interpret=True)
@@ -116,7 +131,7 @@ def test_kernel_stays_int32_under_x64_scope():
     stay int32."""
     slots, probes = _case(100, 200, 20, seed=5)
     ts, tc = build_probe_table_np(slots, 20)
-    with jax.experimental.enable_x64():
+    with jax.enable_x64(True):
         s, c = hash_probe(jnp.asarray(ts), jnp.asarray(tc),
                           jnp.asarray(probes), use_pallas=True,
                           block_n=64, block_t=8, interpret=True)

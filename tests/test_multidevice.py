@@ -57,7 +57,9 @@ def test_sharded_train_step_matches_single_device():
         p1, o1, m1 = jax.jit(step)(params, opt, toks, toks)
 
         # sharded: (pod,data,model) = (2,2,2)
-        mesh = jax.make_mesh((2, 2, 2), ("pod", "data", "model"))
+        mesh = jax.make_mesh(
+            (2, 2, 2), ("pod", "data", "model"),
+            axis_types=(jax.sharding.AxisType.Auto,) * 3)
         rules = make_rules("train", mesh, seq_parallel=True)
         with use_rules(rules):
             psh = safe_params_sharding(params, mesh, rules)

@@ -19,7 +19,7 @@ jax = pytest.importorskip("jax")
 import jax.numpy as jnp  # noqa: E402
 
 from repro.kernels.segment_sum.kernel import (  # noqa: E402
-    masked_segment_reduce_kernel)
+    masked_segment_reduce_kernel, segment_tiling)
 from repro.kernels.segment_sum.ops import masked_segment_reduce  # noqa: E402
 from repro.kernels.segment_sum.ref import (  # noqa: E402
     masked_segment_reduce_ref, reduce_identity)
@@ -57,6 +57,7 @@ def _case(n, num_segments, dtype, seed, p_valid=0.7, p_nan=0.0):
     (1024, 512),         # exact block multiples
     (5, 3),              # smaller than any block
     (2000, 1),           # single segment
+    (5000, 1000),        # 16 x 5 kernel tiles
 ])
 @pytest.mark.parametrize("op", ["min", "max"])
 @pytest.mark.parametrize("use_pallas", [False, True])
@@ -114,15 +115,45 @@ def test_empty_segments_hold_identity(op, use_pallas):
 
 
 @pytest.mark.parametrize("op", ["min", "max"])
+def test_64bit_values_take_xla_segment_ops_under_pallas(op):
+    """With ``use_pallas`` set, 64-bit values (x64 on) route to the XLA
+    segment ops: the Pallas kernels are 32-bit."""
+    vals, ids, valid = _case(1000, 37, np.int64, seed=6)
+    vals = vals * np.int64(1 << 40)
+    want_r, want_c = _numpy_oracle(vals, ids, valid, 37, op)
+    with jax.enable_x64(True):
+        got_r, got_c = masked_segment_reduce(
+            jnp.asarray(vals), jnp.asarray(ids), jnp.asarray(valid), 37,
+            op=op, use_pallas=True, interpret=True)
+        assert got_r.dtype == jnp.int64
+    np.testing.assert_array_equal(np.asarray(got_r), want_r)
+    np.testing.assert_array_equal(np.asarray(got_c), want_c)
+
+
+def _assert_distinct_multi_tile_grids(n, num_segments, tilings):
+    """The tilings compared must really differ, each with >= 2 tiles on
+    both grid axes (segments, rows)."""
+    grids = set()
+    for block_n, block_s in tilings:
+        rb, rows, bs, s_pad = segment_tiling(n, num_segments, block_n,
+                                             block_s)
+        grids.add((s_pad // bs, rows // rb))
+    assert len(grids) == len(tilings), grids
+    assert min(min(g) for g in grids) >= 2, grids
+
+
+@pytest.mark.parametrize("op", ["min", "max"])
 def test_kernel_block_shape_invariance(op):
     """Tiling is a perf knob: output must not depend on block sizes —
     and MIN/MAX make this exact even for floats."""
-    vals, ids, valid = _case(777, 23, np.float32, seed=3, p_nan=0.1)
+    vals, ids, valid = _case(5000, 100, np.float32, seed=3, p_nan=0.1)
+    tilings = ((1024, 8), (2048, 32), (3072, 64))
+    _assert_distinct_multi_tile_grids(len(vals), 100, tilings)
     outs = []
-    for block_n, block_s in ((64, 8), (256, 16), (1024, 512)):
+    for block_n, block_s in tilings:
         r, c = masked_segment_reduce_kernel(
             jnp.asarray(vals), jnp.asarray(ids), jnp.asarray(valid),
-            23, op, block_n=block_n, block_s=block_s, interpret=True)
+            100, op, block_n=block_n, block_s=block_s, interpret=True)
         outs.append((np.asarray(r), np.asarray(c)))
     for r, c in outs[1:]:
         np.testing.assert_array_equal(r, outs[0][0])
@@ -148,7 +179,8 @@ def test_unknown_op_raises():
     ids = jnp.asarray(np.zeros(4, np.int32))
     ok = jnp.asarray(np.ones(4, bool))
     with pytest.raises(ValueError, match="unknown segment reduce op"):
-        masked_segment_reduce(vals, ids, ok, 2, op="median")
+        masked_segment_reduce(vals, ids, ok, 2, op="median",
+                              interpret=True)
 
 
 def test_jax_backend_pallas_minmax_matches_reference():

@@ -13,7 +13,7 @@ jax = pytest.importorskip("jax")
 import jax.numpy as jnp  # noqa: E402
 
 from repro.kernels.segment_sum.kernel import (  # noqa: E402
-    masked_segment_sum_kernel)
+    masked_segment_sum_kernel, segment_tiling)
 from repro.kernels.segment_sum.ops import masked_segment_sum  # noqa: E402
 from repro.kernels.segment_sum.ref import (  # noqa: E402
     masked_segment_sum_ref)
@@ -45,6 +45,7 @@ def _case(n, num_segments, dtype, seed, p_valid=0.7):
     (1024, 512),         # exact block multiples
     (5, 3),              # smaller than any block
     (2000, 1),           # single segment
+    (5000, 1000),        # 16 x 5 kernel tiles
 ])
 @pytest.mark.parametrize("use_pallas", [False, True])
 def test_int32_exact(n, num_segments, use_pallas):
@@ -84,18 +85,48 @@ def test_empty_input():
     s, c = masked_segment_sum(
         jnp.asarray(np.array([], np.float32)),
         jnp.asarray(np.array([], np.int32)),
-        jnp.asarray(np.array([], bool)), 5, use_pallas=True)
+        jnp.asarray(np.array([], bool)), 5, use_pallas=True,
+        interpret=True)
     assert np.asarray(s).shape == (5,)
     assert np.asarray(c).sum() == 0
 
 
+def test_64bit_values_take_xla_segment_ops_under_pallas():
+    """The Pallas kernels are 32-bit; with ``use_pallas`` set, 64-bit
+    values (x64 on) route to the XLA segment ops, never the kernel."""
+    vals, ids, valid = _case(1000, 37, np.int64, seed=6)
+    vals = vals * np.int64(1 << 40)          # needs all 64 bits
+    want_s, want_c = _numpy_oracle(vals, ids, valid, 37)
+    with jax.enable_x64(True):
+        got_s, got_c = masked_segment_sum(
+            jnp.asarray(vals), jnp.asarray(ids), jnp.asarray(valid), 37,
+            use_pallas=True, interpret=True)
+        assert got_s.dtype == jnp.int64
+    np.testing.assert_array_equal(np.asarray(got_s), want_s)
+    np.testing.assert_array_equal(np.asarray(got_c), want_c)
+
+
+def _assert_distinct_multi_tile_grids(n, num_segments, tilings):
+    """The tilings compared must really differ, each with >= 2 tiles on
+    both grid axes (segments, rows)."""
+    grids = set()
+    for block_n, block_s in tilings:
+        rb, rows, bs, s_pad = segment_tiling(n, num_segments, block_n,
+                                             block_s)
+        grids.add((s_pad // bs, rows // rb))
+    assert len(grids) == len(tilings), grids
+    assert min(min(g) for g in grids) >= 2, grids
+
+
 def test_kernel_block_shape_invariance():
     """Tiling is a perf knob: output must not depend on block sizes."""
-    vals, ids, valid = _case(777, 23, np.int32, seed=3)
+    vals, ids, valid = _case(5000, 100, np.int32, seed=3)
+    tilings = ((1024, 8), (2048, 32), (3072, 64))
+    _assert_distinct_multi_tile_grids(len(vals), 100, tilings)
     outs = []
-    for block_n, block_s in ((64, 8), (256, 16), (1024, 512)):
+    for block_n, block_s in tilings:
         s, c = masked_segment_sum_kernel(
-            jnp.asarray(vals), jnp.asarray(ids), jnp.asarray(valid), 23,
+            jnp.asarray(vals), jnp.asarray(ids), jnp.asarray(valid), 100,
             block_n=block_n, block_s=block_s, interpret=True)
         outs.append((np.asarray(s), np.asarray(c)))
     for s, c in outs[1:]:

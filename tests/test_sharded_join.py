@@ -115,6 +115,22 @@ def test_sharded_pallas_probe_matches_reference():
                              backend="reference").fingerprint())
 
 
+def test_sharded_pallas_probe_crosses_table_tiles():
+    """A key span of thousands of slots: the kernel's table axis runs
+    several tiles (default block_t) and its probe axis several blocks."""
+    be = ShardedBackend(use_pallas_probe=True)
+    r = np.random.default_rng(12)
+    left = Table({"k": r.integers(0, 3000, 3000).astype(np.int32),
+                  "x": r.integers(-5, 5, 3000).astype(np.int32)})
+    right = Table({"k": r.integers(0, 3000, 1500).astype(np.int32),
+                   "w": r.normal(size=1500).astype(np.float32)})
+    for how in ("inner", "left"):
+        assert (left.join(right, on=["k"], how=how,
+                          backend=be).fingerprint()
+                == left.join(right, on=["k"], how=how,
+                             backend="reference").fingerprint())
+
+
 def test_sharded_wide_span_and_negative_keys():
     """Hash-partition mode (span past the slot budget) and rebase mode
     (negative keys) both hold the bit-for-bit contract."""
