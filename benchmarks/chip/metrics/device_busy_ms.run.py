@@ -1,0 +1,7 @@
+"""Device (XLA ops, ``kernels/``): the union of device-op intervals in
+the window, per run. Moves ``run_s``."""
+import layers
+
+
+def read(ctx):
+    return layers.device_busy_ms(ctx)
