@@ -1,0 +1,7 @@
+"""Engine and host operators (``exec/``): the ``row_emit`` spans (join
+emission and the right side's gathers) per run. Moves ``run_s``."""
+import layers
+
+
+def read(ctx):
+    return layers.span_ms(ctx, "row_emit")

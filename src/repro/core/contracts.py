@@ -27,6 +27,7 @@ from repro.core.errors import (
     ContractCompositionError,
     ContractRuntimeError,
 )
+from repro.obs import get_recorder
 
 __all__ = [
     "CastDecl", "check_wellformed", "check_edge", "check_node",
@@ -248,9 +249,19 @@ def validate_table(table, schema: type[S.Schema], *,
 
     ``table`` is a :class:`repro.data.tables.Table`. ``elide`` contains
     column names whose null-check was statically discharged by the planner
-    (:func:`provable_postconditions`) and can be skipped.
+    (:func:`provable_postconditions`) and can be skipped. A traced run
+    records the check as one ``contract_check`` span.
     """
     cols = schema.columns()
+    rec = get_recorder()
+    if not rec.enabled:
+        return _check_table(table, cols, schema, elide, name)
+    with rec.span("contract_check", table=name, rows=table.num_rows,
+                  columns=len(cols)):
+        _check_table(table, cols, schema, elide, name)
+
+
+def _check_table(table, cols, schema, elide, name) -> None:
     missing = set(cols) - set(table.column_names())
     if missing:
         raise ContractRuntimeError(

@@ -38,6 +38,7 @@ import numpy as np
 
 from repro import exec as exec_backends
 from repro.data.tables import Expr, Table, _ColumnData
+from repro.obs import get_recorder
 
 __all__ = ["LogicalOp", "Scan", "Filter", "Project", "Aggregate",
            "Join", "Reorder", "Sort", "Limit"]
@@ -54,7 +55,13 @@ def _pred_mask(t: Table, pred: Expr | None) -> np.ndarray | None:
 
 
 class LogicalOp:
-    """Base of the IR ops (frozen dataclasses; see module docstring)."""
+    """Base of the IR ops (frozen dataclasses; see module docstring).
+
+    ``_run`` executes an op: its children first, then the op's own work
+    (``_apply`` over the children's ``(table, stats)`` results), which a
+    traced run records as one ``op.<kind>`` span named by ``_SPAN``.
+    Children run outside their parent's span, so op spans are siblings
+    under the node and never nest."""
 
     def children(self) -> tuple["LogicalOp", ...]:
         return ()
@@ -82,9 +89,19 @@ class LogicalOp:
 
     def execute(self, tables: Mapping[str, Table],
                 stats: "Mapping[str, object] | None" = None) -> Table:
-        return self._exec(tables, stats or {})[0]
+        return self._run(tables, stats or {})[0]
 
-    def _exec(self, tables, stats):
+    def _run(self, tables, stats):
+        ins = [c._run(tables, stats) for c in self.children()]
+        rec = get_recorder()
+        if not rec.enabled:
+            return self._apply(ins)
+        with rec.span(self._SPAN) as sp:
+            out = self._apply(ins)
+            sp.set(rows_out=out[0].num_rows)
+        return out
+
+    def _apply(self, ins):
         raise NotImplementedError
 
 
@@ -107,7 +124,8 @@ class Scan(LogicalOp):
     def scan_tables(self) -> set[str]:
         return {self.table}
 
-    def _exec(self, tables, stats):
+    def _run(self, tables, stats):
+        # zero-copy, so no span of its own
         t = tables[self.table]
         if self.columns is not None:
             keep = set(self.columns)
@@ -121,6 +139,8 @@ class Filter(LogicalOp):
     child: LogicalOp
     pred: Expr
 
+    _SPAN = "op.filter"
+
     def children(self):
         return (self.child,)
 
@@ -130,8 +150,8 @@ class Filter(LogicalOp):
     def describe(self) -> str:
         return f"filter({self.pred.describe()}, {self.child.describe()})"
 
-    def _exec(self, tables, stats):
-        t, _ = self.child._exec(tables, stats)
+    def _apply(self, ins):
+        (t, _), = ins
         return t.filter(self.pred), None
 
 
@@ -139,6 +159,8 @@ class Filter(LogicalOp):
 class Project(LogicalOp):
     child: LogicalOp
     exprs: tuple[Expr, ...]
+
+    _SPAN = "op.project"
 
     def children(self):
         return (self.child,)
@@ -150,8 +172,8 @@ class Project(LogicalOp):
         return (f"project({[e.describe() for e in self.exprs]}, "
                 f"{self.child.describe()})")
 
-    def _exec(self, tables, stats):
-        t, _ = self.child._exec(tables, stats)
+    def _apply(self, ins):
+        (t, _), = ins
         return t.select(list(self.exprs)), None
 
 
@@ -178,6 +200,8 @@ class Aggregate(LogicalOp):
     specs: tuple[tuple[str, str, str], ...]
     strategy: str = "auto"
 
+    _SPAN = "op.aggregate"
+
     def children(self):
         return (self.child,)
 
@@ -188,8 +212,8 @@ class Aggregate(LogicalOp):
         return (f"aggregate(keys={list(self.keys)}, specs={specs}"
                 f"{strat}, {self.child.describe()})")
 
-    def _exec(self, tables, stats):
-        t, ts = self.child._exec(tables, stats)
+    def _apply(self, ins):
+        (t, ts), = ins
         be = exec_backends.resolve(None)
         if self.strategy == "partial":
             try:
@@ -219,6 +243,8 @@ class Join(LogicalOp):
     left_pred: Expr | None = None
     right_pred: Expr | None = None
 
+    _SPAN = "op.join"
+
     def children(self):
         return (self.left, self.right)
 
@@ -235,9 +261,8 @@ class Join(LogicalOp):
             parts.append(f"rpred={self.right_pred.describe()}")
         return f"join({', '.join(parts)})"
 
-    def _exec(self, tables, stats):
-        lt, ls = self.left._exec(tables, stats)
-        rt, rs = self.right._exec(tables, stats)
+    def _apply(self, ins):
+        (lt, ls), (rt, rs) = ins
         be = exec_backends.resolve(None)
         kwargs = {}
         if getattr(be, "accepts_join_stats", False):
@@ -269,6 +294,8 @@ class Sort(LogicalOp):
     child: LogicalOp
     keys: tuple[tuple[str, bool], ...]
 
+    _SPAN = "op.sort"
+
     def children(self):
         return (self.child,)
 
@@ -277,8 +304,8 @@ class Sort(LogicalOp):
                 for name, asc in self.keys]
         return f"sort(keys={keys}, {self.child.describe()})"
 
-    def _exec(self, tables, stats):
-        t, _ = self.child._exec(tables, stats)
+    def _apply(self, ins):
+        (t, _), = ins
         n = len(t)
         # np.lexsort: LAST key is primary -> build (tiebreak, k_last,
         # ..., k_first). Per-key dense ranks via np.unique make object
@@ -313,14 +340,16 @@ class Limit(LogicalOp):
     child: LogicalOp
     n: int
 
+    _SPAN = "op.limit"
+
     def children(self):
         return (self.child,)
 
     def describe(self) -> str:
         return f"limit({self.n}, {self.child.describe()})"
 
-    def _exec(self, tables, stats):
-        t, _ = self.child._exec(tables, stats)
+    def _apply(self, ins):
+        (t, _), = ins
         if len(t) <= self.n:
             return t, None
         data = {nm: _ColumnData(
@@ -353,6 +382,8 @@ class Reorder(LogicalOp):
     sides: tuple[tuple[LogicalOp, tuple[str, ...]], ...]
     order: tuple[int, ...]
 
+    _SPAN = "op.reorder"
+
     def children(self):
         return (self.base,) + tuple(op for op, _ in self.sides)
 
@@ -362,9 +393,9 @@ class Reorder(LogicalOp):
         return (f"reorder(base={self.base.describe()}, "
                 f"sides=[{sides}], order={list(self.order)})")
 
-    def _exec(self, tables, stats):
-        bt, _ = self.base._exec(tables, stats)
-        side_tabs = [op._exec(tables, stats)[0] for op, _ in self.sides]
+    def _apply(self, ins):
+        bt, _ = ins[0]
+        side_tabs = [t for t, _ in ins[1:]]
 
         # canonical output column order: base's, then each side's new
         # columns in *authored* side order (left-copy-wins).

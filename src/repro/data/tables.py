@@ -30,6 +30,7 @@ from typing import Any, Callable, Iterable, Mapping, Sequence
 import numpy as np
 
 from repro import exec as exec_backends
+from repro.obs import get_recorder
 
 __all__ = ["Table", "GroupedTable", "resolve_agg_specs", "col", "lit",
            "str_lit", "arrow_cast", "Expr"]
@@ -179,23 +180,41 @@ class Table:
 
     # -- serialization (object-store snapshots) -------------------------
     def to_blobs(self, store) -> str:
-        """Persist as a content-addressed snapshot; returns manifest key."""
+        """Persist as a content-addressed snapshot; returns manifest key.
+
+        A traced run records it as one ``snapshot_write`` span with the
+        ``columns``, ``rows`` and ``bytes`` (the column arrays handed to
+        the store) written."""
+        rec = get_recorder()
+        if not rec.enabled:
+            return self._to_blobs(store)
+        with rec.span("snapshot_write", columns=len(self._data),
+                      rows=self.num_rows) as sp:
+            sizes: list[int] = []
+            key = self._to_blobs(store, sizes)
+            sp.set(bytes=sum(sizes))
+        return key
+
+    def _to_blobs(self, store, sizes: list[int] | None = None) -> str:
         manifest = {"kind": "table", "columns": {}}
         for name, c in self._data.items():
             vals = c.values
             if vals.dtype == object:
                 enc = np.array([("" if v is None else str(v))
-                                for v in vals])
-                key = store.put_array(enc.astype("U"))
+                                for v in vals]).astype("U")
                 kind = "str"
             elif np.issubdtype(vals.dtype, np.datetime64):
-                key = store.put_array(vals.astype("int64"))
+                enc = vals.astype("int64")
                 kind = "datetime"
             else:
-                key = store.put_array(vals)
+                enc = vals
                 kind = "plain"
+            key = store.put_array(enc)
             vkey = (store.put_array(c.valid)
                     if c.valid is not None else None)
+            if sizes is not None:
+                sizes.append(enc.nbytes + (0 if c.valid is None
+                                           else c.valid.nbytes))
             # dtype recorded so schema inference over a snapshot (the
             # SQL front door's catalog discovery) reads the manifest
             # only, never the column blobs; "str"/"datetime" kinds pin
@@ -207,12 +226,34 @@ class Table:
 
     @classmethod
     def from_blobs(cls, store, key: str) -> "Table":
+        """Load a snapshot. A traced run records it as one
+        ``snapshot_read`` span with the ``columns`` (``str_columns`` of
+        them strings), ``rows`` and ``bytes`` (the column arrays as
+        stored) read."""
+        rec = get_recorder()
+        if not rec.enabled:
+            return cls._from_blobs(store, key)
+        with rec.span("snapshot_read") as sp:
+            sizes: list[int] = []
+            t = cls._from_blobs(store, key, sizes)
+            sp.set(columns=len(t._data),
+                   str_columns=sum(c.values.dtype == object
+                                   for c in t._data.values()),
+                   rows=t.num_rows, bytes=sum(sizes))
+        return t
+
+    @classmethod
+    def _from_blobs(cls, store, key: str,
+                    sizes: list[int] | None = None) -> "Table":
         manifest = store.get_json(key)
         data: dict[str, _ColumnData] = {}
         for name, m in manifest["columns"].items():
             vals = store.get_array(m["values"])
             valid = (store.get_array(m["valid"])
                      if m["valid"] is not None else None)
+            if sizes is not None:
+                sizes.append(vals.nbytes + (0 if valid is None
+                                            else valid.nbytes))
             if m["kind"] == "str":
                 vals = _canon_str_array(vals)
                 if valid is not None:   # true roundtrip: restore None

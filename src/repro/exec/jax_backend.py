@@ -32,10 +32,11 @@ import jax
 import jax.numpy as jnp
 
 from repro.exec.base import fill_value
-from repro.exec.vectorized import VectorizedBackend
+from repro.exec.vectorized import _NOOP_CTX, VectorizedBackend
 from repro.kernels import fallback
 from repro.kernels.segment_sum.ops import (masked_segment_reduce,
                                            masked_segment_sum)
+from repro.obs import get_recorder
 
 __all__ = ["JaxBackend"]
 
@@ -78,14 +79,31 @@ class JaxBackend(VectorizedBackend):
     def _segment_ids(order: np.ndarray, bounds: np.ndarray,
                      grp_order: np.ndarray, n_groups: int,
                      n: int) -> np.ndarray:
-        """Per-row segment ids in output (first-appearance) order, from
-        the group-run structure the vectorized base already computed."""
-        run_lengths = np.diff(np.r_[bounds, n])
-        inv_code = np.empty(n, dtype=np.int64)
-        inv_code[order] = np.repeat(np.arange(n_groups), run_lengths)
-        rank = np.empty(n_groups, dtype=np.int64)
-        rank[grp_order] = np.arange(n_groups)
-        return rank[inv_code]
+        """Per-row int32 segment ids in output (first-appearance) order,
+        from the group-run structure the vectorized base already
+        computed; a traced run records them as a ``key_codes`` span over
+        the one group id."""
+        rec = get_recorder()
+        with (rec.span("key_codes", rows=n, keys=1, object_keys=0)
+              if rec.enabled else _NOOP_CTX):
+            run_lengths = np.diff(np.r_[bounds, n])
+            inv_code = np.empty(n, dtype=np.int64)
+            inv_code[order] = np.repeat(np.arange(n_groups), run_lengths)
+            rank = np.empty(n_groups, dtype=np.int64)
+            rank[grp_order] = np.arange(n_groups)
+            return rank[inv_code].astype(np.int32)
+
+    @staticmethod
+    def _kernel_span(rec, op: str, values: np.ndarray, gid: np.ndarray,
+                     ok: np.ndarray, n_groups: int):
+        """The ``kernel`` span of one device call, from the host-to-device
+        copies of values, segment ids and mask to the end of the fetch
+        (the caller sets ``d2h_bytes``); a no-op context when off."""
+        if not rec.enabled:
+            return _NOOP_CTX
+        return rec.span("kernel", op=op, rows=len(values),
+                        segments=n_groups,
+                        h2d_bytes=values.nbytes + gid.nbytes + ok.nbytes)
 
     def _aggregate(self, values: np.ndarray, ok: np.ndarray,
                    order: np.ndarray, bounds: np.ndarray,
@@ -96,13 +114,17 @@ class JaxBackend(VectorizedBackend):
                                       grp_order, n_groups)
         gid = self._segment_ids(order, bounds, grp_order, n_groups,
                                 len(values))
-        sums, counts = masked_segment_sum(
-            jnp.asarray(values), jnp.asarray(gid.astype(np.int32)),
-            jnp.asarray(ok), n_groups,
-            use_pallas=self.use_pallas, interpret=self.interpret)
+        with self._kernel_span(get_recorder(), "jax.segment_sum", values,
+                               gid, ok, n_groups) as sp:
+            sums, counts = masked_segment_sum(
+                jnp.asarray(values), jnp.asarray(gid), jnp.asarray(ok),
+                n_groups, use_pallas=self.use_pallas,
+                interpret=self.interpret)
+            sums, counts = np.asarray(sums), np.asarray(counts)
+            if sp is not None:
+                sp.set(d2h_bytes=sums.nbytes + counts.nbytes)
         # empty segments already hold 0 == the canonical numeric fill
-        return (np.asarray(sums).astype(values.dtype, copy=False),
-                np.asarray(counts) > 0)
+        return sums.astype(values.dtype, copy=False), counts > 0
 
     def _agg_minmax(self, fn: str, values: np.ndarray, ok: np.ndarray,
                     order: np.ndarray, bounds: np.ndarray,
@@ -115,13 +137,18 @@ class JaxBackend(VectorizedBackend):
                                        grp_order, n_groups)
         gid = self._segment_ids(order, bounds, grp_order, n_groups,
                                 len(values))
-        red, counts = masked_segment_reduce(
-            jnp.asarray(values), jnp.asarray(gid.astype(np.int32)),
-            jnp.asarray(ok), n_groups, op=fn,
-            use_pallas=self.use_pallas, interpret=self.interpret)
+        with self._kernel_span(get_recorder(), "jax.segment_reduce",
+                               values, gid, ok, n_groups) as sp:
+            red, counts = masked_segment_reduce(
+                jnp.asarray(values), jnp.asarray(gid), jnp.asarray(ok),
+                n_groups, op=fn, use_pallas=self.use_pallas,
+                interpret=self.interpret)
+            red, counts = np.array(red), np.asarray(counts)
+            if sp is not None:
+                sp.set(d2h_bytes=red.nbytes + counts.nbytes)
         # empty segments hold the reduce identity (±inf / dtype
         # extremes), not the canonical fill — rewrite them.
-        red = np.array(red).astype(vdt, copy=False)
-        has = np.asarray(counts) > 0
+        red = red.astype(vdt, copy=False)
+        has = counts > 0
         red[~has] = fill_value(vdt)
         return red, has

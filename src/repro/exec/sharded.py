@@ -84,7 +84,6 @@ inherited jax/vectorized path. Filter and concat stay inherited.
 """
 from __future__ import annotations
 
-import contextlib
 import functools
 import os
 from typing import Sequence
@@ -97,8 +96,9 @@ import jax.numpy as jnp
 from repro.exec.base import (AggSpec, Columns, _column_length, fill_value,
                              normalize_agg_specs, payload_validity)
 from repro.exec.jax_backend import JaxBackend
-from repro.exec.vectorized import (_and_key_validity, _join_codes,
-                                   dense_span_affordable)
+from repro.exec.vectorized import (_NOOP_CTX, _and_key_validity,
+                                   _join_codes, dense_span_affordable,
+                                   key_codes_span)
 from repro.kernels import fallback
 from repro.kernels.hash_join.ops import hash_probe, masked_hash_probe
 from repro.kernels.segment_sum.ops import (masked_segment_reduce,
@@ -115,8 +115,6 @@ __all__ = ["ShardedBackend"]
 # key spaces hash-partition ("hash" mode); anything that fits int32
 # still ships as int32.
 MAX_TABLE_SPAN = 1 << 26
-
-_NOOP_CTX = contextlib.nullcontext()
 
 
 def _next_pow2(n: int) -> int:
@@ -529,7 +527,9 @@ class ShardedBackend(JaxBackend):
                 reason=f"{ndev} devices exceeds the uint8 bucket space "
                        f"(255)")
 
-        keyed = self._device_keys(left, right, on)
+        rec = get_recorder()
+        with key_codes_span(rec, on, left, right):
+            keyed = self._device_keys(left, right, on)
         if keyed is None:               # cannot lower: vectorized path
             return self._host_fallback(
                 left, right, on, how, probe_mask,
@@ -555,14 +555,15 @@ class ShardedBackend(JaxBackend):
         # even ship.
         fused = (probe_mask is not None and self.use_pallas_probe
                  and span_shard > 0)
-        if probe_mask is not None and not fused:
-            sent = lk.dtype.type(np.iinfo(lk.dtype).max)
-            lk = np.where(np.asarray(probe_mask, dtype=bool), lk, sent)
-
-        lb = _buckets(lk, ndev, span_shard)
-        rb = _buckets(rk, ndev, span_shard)
-        l_slab, l_idx, cap_l = _partition(lk, lb, ndev)
-        r_slab, r_idx, cap_r = _partition(rk, rb, ndev)
+        with key_codes_span(rec, on, left, right):
+            if probe_mask is not None and not fused:
+                sent = lk.dtype.type(np.iinfo(lk.dtype).max)
+                lk = np.where(np.asarray(probe_mask, dtype=bool), lk,
+                              sent)
+            lb = _buckets(lk, ndev, span_shard)
+            rb = _buckets(rk, ndev, span_shard)
+            l_slab, l_idx, cap_l = _partition(lk, lb, ndev)
+            r_slab, r_idx, cap_r = _partition(rk, rb, ndev)
         if ndev * cap_l >= 2**31 or ndev * cap_r >= 2**31:
             # padded per-shard lane counts must fit the int32 arrival
             # positions the probes pack — possible past ~2e9 rows with
@@ -589,10 +590,10 @@ class ShardedBackend(JaxBackend):
             args = (l_slab, m_slab, r_slab)
         else:
             args = (l_slab, r_slab)
-        rec = get_recorder()
         kernel_ctx = _NOOP_CTX
         if rec.enabled:
-            # every slab in `args` crosses the mesh through all_to_all
+            # every slab in `args` is copied to the mesh and crosses it
+            # through all_to_all
             bytes_moved = sum(a.nbytes for a in args)
             # valid rows each owner shard probes / builds (slab padding
             # and unmatchable rows excluded)
@@ -600,18 +601,24 @@ class ShardedBackend(JaxBackend):
                 "kernel", op="sharded.exchange_probe", ndev=ndev,
                 mode=("table" if span_shard > 0 else "hash"),
                 fused_mask=fused, all_to_all_bytes=bytes_moved,
+                rows=n_left + n_right, segments=ndev * span_shard,
+                h2d_bytes=bytes_moved,
                 rows_left=n_left, rows_right=n_right,
                 rows_left_per_shard=(l_idx >= 0).sum(axis=(0, 2)).tolist(),
                 rows_right_per_shard=(r_idx >= 0).sum(
                     axis=(0, 2)).tolist())
             rec.metrics.histogram(
                 "sharded.all_to_all_bytes").observe(bytes_moved)
-        # the packed/wide probes carry int64 intermediates; the x64
-        # scope is thread-local and only governs types traced inside.
-        with kernel_ctx:
+        # the span ends after the fetch, where the host waits for the
+        # device. The packed/wide probes carry int64 intermediates; the
+        # x64 scope is thread-local and only governs types traced inside.
+        with kernel_ctx as sp:
             with jax.enable_x64(True):
                 out = fn(*args)
-        starts, counts, gidx = (np.asarray(o) for o in out)
+            starts, counts, gidx = (np.asarray(o) for o in out)
+            if sp is not None:
+                sp.set(d2h_bytes=starts.nbytes + counts.nbytes
+                       + gidx.nbytes)
 
         # map device results back through the kept permutation: the
         # grouped layout is the per-shard arrival order permuted by
@@ -811,9 +818,6 @@ class ShardedBackend(JaxBackend):
                 return (v - v.dtype.type(lo)).astype(np.int32)
             return (v.astype(np.int64) - lo).astype(np.int32)
 
-        gid = rebase(kv)
-        if any_null:
-            gid[~kok] = np.int32(span)
         chunk = -(-n // ndev)
         pad = ndev * chunk - n
 
@@ -823,29 +827,34 @@ class ShardedBackend(JaxBackend):
                     [arr, np.full(pad, fill, dtype=arr.dtype)])
             return arr.reshape(ndev, chunk)
 
-        # first-appearance per slot stays on the host: the rebase
-        # already materialized gid, so a reversed fancy assignment
-        # (later writes win, so the reversed order leaves each slot
-        # holding its FIRST row) beats shipping a row-id slab and a
-        # whole extra segment reduce through the exchange.
-        first = np.full(n_slots, n, dtype=np.int64)
-        first[gid[::-1]] = np.arange(n - 1, -1, -1, dtype=np.int64)
+        rec = get_recorder()
+        with key_codes_span(rec, keys, cols):
+            gid = rebase(kv)
+            if any_null:
+                gid[~kok] = np.int32(span)
+            # first-appearance per slot stays on the host: the rebase
+            # already materialized gid, so a reversed fancy assignment
+            # (later writes win, so the reversed order leaves each slot
+            # holding its FIRST row) beats shipping a row-id slab and a
+            # whole extra segment reduce through the exchange.
+            first = np.full(n_slots, n, dtype=np.int64)
+            first[gid[::-1]] = np.arange(n - 1, -1, -1, dtype=np.int64)
+            codes = np.flatnonzero(first < n)    # the slots in use
 
-        gid_slab = slab(gid, np.int32(0))    # padding: slot 0, ok=False
-        col_sig = []
-        col_slabs = []
-        col_names = list(want)
-        for name in col_names:
-            values, valid = cols[name]
-            ok = payload_validity(values, valid)
-            col_sig.append((values.dtype.str,
-                            tuple(sorted(want[name]))))
-            col_slabs.append(slab(values, fill_value(values.dtype)))
-            col_slabs.append(slab(ok, False))
+            gid_slab = slab(gid, np.int32(0))    # padding: slot 0, ok=False
+            col_sig = []
+            col_slabs = []
+            col_names = list(want)
+            for name in col_names:
+                values, valid = cols[name]
+                ok = payload_validity(values, valid)
+                col_sig.append((values.dtype.str,
+                                tuple(sorted(want[name]))))
+                col_slabs.append(slab(values, fill_value(values.dtype)))
+                col_slabs.append(slab(ok, False))
 
         fn = _partial_agg_fn(ndev, seg_shard, tuple(col_sig),
                              self.use_pallas, self.interpret)
-        rec = get_recorder()
         kernel_ctx = _NOOP_CTX
         if rec.enabled:
             # the exchange ships one lane per (shard, key slot) per
@@ -856,19 +865,29 @@ class ShardedBackend(JaxBackend):
             bytes_moved = sum(
                 lanes * (4 + np.dtype(dt).itemsize * len(stats))
                 for dt, stats in col_sig)
+            # groups_per_shard: the slots in use that each owner shard
+            # holds after the exchange (its contiguous seg_shard range)
             kernel_ctx = rec.span(
                 "kernel", op="sharded.partial_agg", ndev=ndev,
-                rows=n, slots=n_slots, all_to_all_bytes=bytes_moved,
+                rows=n, slots=n_slots, segments=nseg,
+                all_to_all_bytes=bytes_moved,
+                h2d_bytes=gid_slab.nbytes + sum(a.nbytes
+                                                for a in col_slabs),
                 rows_per_shard=[min(chunk, max(0, n - d * chunk))
-                                for d in range(ndev)])
+                                for d in range(ndev)],
+                groups_per_shard=np.bincount(
+                    codes // seg_shard, minlength=ndev).tolist())
             rec.metrics.histogram(
                 "sharded.all_to_all_bytes").observe(bytes_moved)
-        # the packed strategy sorts int64-packed lanes; the x64 scope
-        # is thread-local and only governs types traced inside.
-        with kernel_ctx:
+        # the span ends after the fetch, where the host waits for the
+        # device. The packed strategy sorts int64-packed lanes; the x64
+        # scope is thread-local and only governs types traced inside.
+        with kernel_ctx as sp:
             with jax.enable_x64(True):
                 outs = [np.asarray(o).reshape(-1) for o in
                         fn(gid_slab, *col_slabs)]
+            if sp is not None:
+                sp.set(d2h_bytes=sum(o.nbytes for o in outs))
 
         # unpack in the body's emission order
         stats_of: dict[str, dict[str, np.ndarray]] = {}
@@ -884,7 +903,6 @@ class ShardedBackend(JaxBackend):
 
         # host finalize: presence + first-appearance order from ONE
         # small argsort over distinct keys (never over rows)
-        codes = np.flatnonzero(first < n)
         out_codes = codes[np.argsort(first[codes], kind="stable")]
         kdt = kv.dtype
         if kdt.kind == "u" and kdt.itemsize == 8:

@@ -35,6 +35,7 @@ column kind (group structure stays vectorized).
 """
 from __future__ import annotations
 
+import contextlib
 from typing import Sequence
 
 import numpy as np
@@ -42,8 +43,12 @@ import numpy as np
 from repro.exec.base import (AggSpec, Backend, Columns, _column_length,
                              fill_value, normalize_agg_specs,
                              payload_validity)
+from repro.obs import get_recorder
 
-__all__ = ["VectorizedBackend", "dense_span_affordable", "reduce_ident"]
+__all__ = ["VectorizedBackend", "dense_span_affordable", "reduce_ident",
+           "key_codes_span"]
+
+_NOOP_CTX = contextlib.nullcontext()
 
 
 def reduce_ident(dtype: np.dtype, op: str):
@@ -63,6 +68,20 @@ def dense_span_affordable(span: int, n_rows: int) -> bool:
     bincount fast path below AND for the ``auto`` policy's
     dense-int-key row (exec/auto.py) — tune it in one place."""
     return span <= 4 * n_rows + 1024
+
+
+def key_codes_span(rec, keys: Sequence[str], *sides: Columns):
+    """The ``key_codes`` span over host key lowering, factorization and
+    partitioning of ``keys`` in the column sets ``sides`` (``rows`` of
+    all sides, ``keys``, and ``object_keys``: key columns that are
+    Python objects on some side); a no-op context when tracing is off."""
+    if not rec.enabled:
+        return _NOOP_CTX
+    return rec.span(
+        "key_codes", rows=sum(_column_length(c) for c in sides),
+        keys=len(keys),
+        object_keys=sum(any(c[k][0].dtype == object for c in sides)
+                        for k in keys))
 
 
 # ---------------------------------------------------------------------------
@@ -238,22 +257,28 @@ class VectorizedBackend(Backend):
     # -- join -----------------------------------------------------------
     def hash_join(self, left: Columns, right: Columns,
                   on: Sequence[str], how: str = "inner") -> Columns:
-        fast = self._single_key_probe(left, right, on)
-        if fast is not None:
-            n_left, starts, counts, ridx = fast
-        else:
-            lcodes, rcodes = _join_codes(left, right, on)
-            n_left = len(lcodes)
-            rvalid = np.flatnonzero(rcodes >= 0)
-            order = np.argsort(rcodes[rvalid], kind="stable")
-            rsorted = rcodes[rvalid][order]
-            ridx = rvalid[order]        # right rows, sorted by code,
-            #                             occurrence order within a code
-            starts = np.searchsorted(rsorted, lcodes, side="left")
-            ends = np.searchsorted(rsorted, lcodes, side="right")
-            counts = np.where(lcodes >= 0, ends - starts, 0)
+        with key_codes_span(get_recorder(), on, left, right):
+            n_left, starts, counts, ridx = self._probe(left, right, on)
         return self._emit_join(left, right, how, n_left, starts, counts,
                                ridx)
+
+    def _probe(self, left: Columns, right: Columns, on: Sequence[str]):
+        """(n_left, starts, counts, ridx) as ``_single_key_probe``
+        documents, through the joint key codes where no fast path
+        applies."""
+        fast = self._single_key_probe(left, right, on)
+        if fast is not None:
+            return fast
+        lcodes, rcodes = _join_codes(left, right, on)
+        rvalid = np.flatnonzero(rcodes >= 0)
+        order = np.argsort(rcodes[rvalid], kind="stable")
+        rsorted = rcodes[rvalid][order]
+        ridx = rvalid[order]        # right rows, sorted by code,
+        #                             occurrence order within a code
+        starts = np.searchsorted(rsorted, lcodes, side="left")
+        ends = np.searchsorted(rsorted, lcodes, side="right")
+        counts = np.where(lcodes >= 0, ends - starts, 0)
+        return len(lcodes), starts, counts, ridx
 
     def masked_hash_join(self, left: Columns, right: Columns,
                          on: Sequence[str], how: str = "inner", *,
@@ -291,8 +316,23 @@ class VectorizedBackend(Backend):
         contiguous, in right-occurrence order); left row ``i``'s matches
         are ``ridx[starts[i] : starts[i] + counts[i]]``. The grouped
         layout need not be globally key-sorted — the sharded backend
-        concatenates per-shard runs — only per-key contiguous.
+        concatenates per-shard runs — only per-key contiguous. A traced
+        run records it as one ``row_emit`` span (``rows_out``,
+        ``columns``), the right side's gathers included.
         """
+        rec = get_recorder()
+        if not rec.enabled:
+            return self._emit_rows(left, right, how, n_left, starts,
+                                   counts, ridx)
+        with rec.span("row_emit") as sp:
+            out = self._emit_rows(left, right, how, n_left, starts,
+                                  counts, ridx)
+            sp.set(rows_out=_column_length(out), columns=len(out))
+        return out
+
+    def _emit_rows(self, left: Columns, right: Columns, how: str,
+                   n_left: int, starts: np.ndarray, counts: np.ndarray,
+                   ridx: np.ndarray) -> Columns:
         unique_match = int(counts.max()) <= 1 if len(counts) else True
         if how == "inner":
             if unique_match:
@@ -428,7 +468,9 @@ class VectorizedBackend(Backend):
     def group_by_agg(self, cols: Columns, keys: Sequence[str],
                      specs: Sequence[AggSpec]) -> Columns:
         specs = normalize_agg_specs(cols, keys, specs)
-        order, bounds, grp_order, rep = self._runs_for_keys(cols, keys)
+        with key_codes_span(get_recorder(), keys, cols):
+            order, bounds, grp_order, rep = self._runs_for_keys(cols,
+                                                                keys)
         n_groups = len(rep)
         data: dict[str, tuple[np.ndarray, np.ndarray | None]] = {}
         for kname in keys:
