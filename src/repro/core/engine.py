@@ -45,7 +45,8 @@ from repro.core.store import ObjectStore
 from repro.data.tables import Table
 from repro.obs import get_recorder
 
-__all__ = ["cache_key", "NodeCache", "ExecutionOutcome", "PlanExecutor"]
+__all__ = ["cache_key", "NodeCache", "ExecutionOutcome", "PlanExecutor",
+           "source_columns"]
 
 
 def cache_key(step: PlanStep,
@@ -95,6 +96,29 @@ def cache_key(step: PlanStep,
     for param in sorted(input_snapshots):
         h.update(f"|{param}={input_snapshots[param]}".encode())
     return h.hexdigest()[:32]
+
+
+def source_columns(plan: Plan) -> dict[str, frozenset[str] | None]:
+    """The columns to load of each table the plan's steps take as
+    inputs: the union, over every step that reads the table, of the
+    columns that step's ``Scan`` ops of the table keep. ``None`` (the
+    whole table) where a step may look past its scans: an opaque node
+    body (no logical tree), a ``Scan`` that keeps every column, or a
+    step that takes the table as an input without scanning it. Every
+    step therefore computes what it would over the whole table; only
+    what the optimizer's ``column_pruning`` elided is left unread."""
+    need: dict[str, set[str] | None] = {}
+    for step in plan.steps:
+        for table in set(step.node.inputs.values()):
+            scans = ([s for s in step.logical.scans() if s.table == table]
+                     if step.logical is not None else [])
+            if scans and all(s.columns is not None for s in scans):
+                if need.setdefault(table, set()) is not None:
+                    need[table].update(c for s in scans for c in s.columns)
+            else:
+                need[table] = None
+    return {t: None if cols is None else frozenset(cols)
+            for t, cols in need.items()}
 
 
 class NodeCache:
@@ -170,6 +194,9 @@ class PlanExecutor:
         widest = max((len(w) for w in plan.waves), default=1)
         self.max_workers = max(1, max_workers if max_workers is not None
                                else min(16, widest))
+        # a source is loaded once per execute, with every column any
+        # step scans of it
+        self.source_columns = source_columns(plan)
 
     # ------------------------------------------------------------------
     def execute(self, resolve_source: Callable[[str], str], *,
@@ -193,13 +220,15 @@ class PlanExecutor:
 
         def materialize(table: str) -> Table:
             # upstream outputs were installed between waves; only source
-            # tables are lazily loaded (and memoized) here.
+            # tables are lazily loaded (and memoized) here, with the
+            # columns the plan's scans of them keep.
             if table in tables:
                 return tables[table]
             with mat_lock:
                 if table not in tables:
-                    tables[table] = Table.from_blobs(self.store,
-                                                     snaps[table])
+                    tables[table] = Table.from_blobs(
+                        self.store, snaps[table],
+                        self.source_columns.get(table))
                 return tables[table]
 
         rec = get_recorder()

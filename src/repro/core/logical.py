@@ -32,7 +32,7 @@ Design rules:
 from __future__ import annotations
 
 import dataclasses
-from typing import Mapping, Sequence
+from typing import Iterator, Mapping, Sequence
 
 import numpy as np
 
@@ -81,11 +81,13 @@ class LogicalOp:
                     for e in self._own_exprs())
                 and all(c.is_structural() for c in self.children()))
 
-    def scan_tables(self) -> set[str]:
-        out: set[str] = set()
+    def scans(self) -> Iterator["Scan"]:
+        """Every ``Scan`` of the tree: the only ops that read tables."""
         for c in self.children():
-            out |= c.scan_tables()
-        return out
+            yield from c.scans()
+
+    def scan_tables(self) -> set[str]:
+        return {s.table for s in self.scans()}
 
     def execute(self, tables: Mapping[str, Table],
                 stats: "Mapping[str, object] | None" = None) -> Table:
@@ -121,8 +123,8 @@ class Scan(LogicalOp):
             return f"scan({self.table})"
         return f"scan({self.table}, cols={sorted(self.columns)})"
 
-    def scan_tables(self) -> set[str]:
-        return {self.table}
+    def scans(self) -> Iterator["Scan"]:
+        yield self
 
     def _run(self, tables, stats):
         # zero-copy, so no span of its own

@@ -225,35 +225,47 @@ class Table:
         return store.put_json(manifest)
 
     @classmethod
-    def from_blobs(cls, store, key: str) -> "Table":
-        """Load a snapshot. A traced run records it as one
-        ``snapshot_read`` span with the ``columns`` (``str_columns`` of
-        them strings), ``rows`` and ``bytes`` (the column arrays as
-        stored) read."""
+    def from_blobs(cls, store, key: str,
+                   columns: Iterable[str] | None = None) -> "Table":
+        """Load a snapshot. ``columns`` given, only the manifest's
+        columns named there are fetched and decoded, in the manifest's
+        order; names the manifest lacks are skipped. ``None`` reads
+        every column.
+
+        A traced run records it as one ``snapshot_read`` span with the
+        ``columns`` (``str_columns`` of them strings), ``rows`` and
+        ``bytes`` (the column arrays as stored) read, and the
+        ``columns_skipped``: manifest columns left unread."""
         rec = get_recorder()
         if not rec.enabled:
-            return cls._from_blobs(store, key)
+            return cls._from_blobs(store, key, columns)
         with rec.span("snapshot_read") as sp:
-            sizes: list[int] = []
-            t = cls._from_blobs(store, key, sizes)
-            sp.set(columns=len(t._data),
+            read = {"bytes": 0, "skipped": 0}
+            t = cls._from_blobs(store, key, columns, read)
+            sp.set(columns=len(t._data), columns_skipped=read["skipped"],
                    str_columns=sum(c.values.dtype == object
                                    for c in t._data.values()),
-                   rows=t.num_rows, bytes=sum(sizes))
+                   rows=t.num_rows, bytes=read["bytes"])
         return t
 
     @classmethod
     def _from_blobs(cls, store, key: str,
-                    sizes: list[int] | None = None) -> "Table":
+                    columns: Iterable[str] | None = None,
+                    read: dict[str, int] | None = None) -> "Table":
         manifest = store.get_json(key)
+        keep = None if columns is None else set(columns)
         data: dict[str, _ColumnData] = {}
         for name, m in manifest["columns"].items():
+            if keep is not None and name not in keep:
+                if read is not None:
+                    read["skipped"] += 1
+                continue
             vals = store.get_array(m["values"])
             valid = (store.get_array(m["valid"])
                      if m["valid"] is not None else None)
-            if sizes is not None:
-                sizes.append(vals.nbytes + (0 if valid is None
-                                            else valid.nbytes))
+            if read is not None:
+                read["bytes"] += vals.nbytes + (0 if valid is None
+                                                else valid.nbytes)
             if m["kind"] == "str":
                 vals = _canon_str_array(vals)
                 if valid is not None:   # true roundtrip: restore None

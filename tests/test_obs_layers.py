@@ -108,12 +108,27 @@ def test_query_spans_open_under_node_and_ops():
 
 
 def test_snapshot_spans_count_rows_columns_and_bytes():
-    _, rec, res = _traced_query("vectorized")
+    c, rec, res = _traced_query("vectorized")
     tables = _tables()
     by_rows = {s.attrs["rows"]: s for s in rec.spans("snapshot_read")}
+    # the optimized query scans f's k and v, leaving its string s
+    # unread, and every column of d
+    scanned = {"f": ("k", "v"), "d": ("k", "g")}
     for name, t in tables.items():
         s = by_rows[t.num_rows]
+        kept = Table(_data={n: t._data[n] for n in scanned[name]})
+        assert s.attrs["columns"] == len(scanned[name])
+        assert s.attrs["columns_skipped"] == (1 if name == "f" else 0)
+        assert s.attrs["str_columns"] == 0
+        assert s.attrs["bytes"] == _stored_bytes(kept)
+    # unoptimized, nothing is pruned and every column is read
+    with obs.tracing() as whole:
+        c.sql(QUERY, cache=False, optimizer_passes=())
+    whole_rows = {s.attrs["rows"]: s for s in whole.spans("snapshot_read")}
+    for name, t in tables.items():
+        s = whole_rows[t.num_rows]
         assert s.attrs["columns"] == len(t.column_names())
+        assert s.attrs["columns_skipped"] == 0
         assert s.attrs["str_columns"] == (1 if name == "f" else 0)
         assert s.attrs["bytes"] == _stored_bytes(t)
     (write,) = rec.spans("snapshot_write")
@@ -122,6 +137,7 @@ def test_snapshot_spans_count_rows_columns_and_bytes():
     assert write.attrs["columns"] == len(out.column_names())
     assert write.attrs["bytes"] == _stored_bytes(out)
     assert by_rows[out.num_rows].attrs["bytes"] == write.attrs["bytes"]
+    assert by_rows[out.num_rows].attrs["columns_skipped"] == 0
     (check,) = rec.spans("contract_check")
     assert check.attrs == {"table": "query", "rows": out.num_rows,
                            "columns": len(out.column_names())}
@@ -228,8 +244,13 @@ def test_untraced_path_opens_no_span_inside_the_node(backend):
     try:
         with rexec.use_backend(backend):
             res = c.sql(QUERY, cache=False)
+            # a projected read, as the engine's source loads make
+            part = Table.from_blobs(c.store,
+                                    c.catalog.head("main").tables["f"],
+                                    columns=("k",))
     finally:
         obs.install(prev)
+    assert part.column_names() == ["k"]
     assert not NEW_SPANS & set(watching.asked), watching.asked
     assert res.table.fingerprint() == traced.table.fingerprint()
 
