@@ -68,12 +68,14 @@ ships one lane per (shard, key slot) instead of one per input row;
 the key's owner shard combines the partials (add for SUM/COUNT,
 min/max for MIN/MAX), and MEAN is finalized from the shipped
 sum+count after the exchange — it is never shipped as a value. The
-per-shard reduction mirrors the join's two probe strategies: <= 32-bit
-integer values take a packed single-operand sort (counts/sums/min/max
-all fall out of run boundaries — no scatter, which XLA:CPU serializes
-per row), while float and 64-bit values, plus the ``use_pallas`` TPU
-target, run the masked ``kernels/segment_sum`` family (NaN
-propagation baked into each partial). First-appearance output order
+per-shard reduction strategy follows the platform of the mesh's
+devices: on a CPU mesh, <= 32-bit integer values take a packed
+single-operand sort (counts/sums/min/max all fall out of run
+boundaries — no scatter, which XLA:CPU serializes per row); on any
+other platform, and for float and 64-bit values everywhere, every
+column runs the masked ``kernels/segment_sum`` family (XLA scatter,
+or the Pallas kernels under ``use_pallas``; NaN propagation baked
+into each partial). First-appearance output order
 never rides the exchange at all: the host already materialized the
 dense slot codes for the rebase, so one reversed fancy assignment
 recovers each slot's first row and one small argsort over distinct
@@ -302,32 +304,57 @@ def _probe_fn(ndev: int, cap_l: int, cap_r: int, span_shard: int,
     return jax.jit(mapped, in_shardings=(shard,) * len(in_specs))
 
 
+def _agg_strategy(dtype: np.dtype, platform: str, use_pallas: bool
+                  ) -> str:
+    """Per-column reduction strategy of the mesh's partial aggregation:
+    "packed" for <= 32-bit integer values on a CPU mesh without
+    ``use_pallas``, "segment" everywhere else (see ``_partial_agg_fn``).
+    """
+    if (platform == "cpu" and not use_pallas and dtype.kind in "iu"
+            and dtype.itemsize <= 4):
+        return "packed"
+    return "segment"
+
+
+def _agg_strategies(ndev: int, col_sig: tuple, use_pallas: bool
+                    ) -> tuple[str, ...]:
+    """One strategy per ``col_sig`` column, from the mesh's platform."""
+    platform = _get_mesh(ndev).devices.flat[0].platform
+    return tuple(_agg_strategy(np.dtype(dt), platform, use_pallas)
+                 for dt, _stats in col_sig)
+
+
 @functools.lru_cache(maxsize=64)
 def _partial_agg_fn(ndev: int, seg_shard: int, col_sig: tuple,
-                    use_pallas: bool, interpret: bool):
+                    use_pallas: bool, interpret: bool,
+                    strategies: tuple[str, ...] | None = None):
     """Build + jit the shard_map'd partial-aggregation exchange for one
     static signature. ``col_sig`` is a tuple of (dtype str, stats
     tuple) per distinct value column, stats drawn from
     {"sum", "min", "max"} — COUNT partials are always produced (they
-    double as output validity and the MEAN divisor).
+    double as output validity and the MEAN divisor). ``strategies``
+    holds one of "packed" / "segment" per column; None reads them
+    from the mesh devices' platform (``_agg_strategies``), as the
+    backend does before it calls.
 
     Protocol per shard: reduce local rows to (nseg,) partial vectors,
     ``all_to_all`` each vector (one lane per (shard, key slot) — never
     one per row), then the owner shard combines its slot range: add
     for sum/count, min/max for min/max. Two per-column reduction
-    strategies, the aggregation twin of the join's packed/table probe
-    split:
+    strategies, both exact for integers; the platform picks:
 
-    - packed (the CPU-mesh default for <= 32-bit integer values): one
+    - packed (CPU meshes, <= 32-bit integer values): one
       single-operand sort of ``slot << 32 | order-biased value`` —
       counts are run lengths, the sum is a difference of two lanes of
       one wrapping cumsum (modular, so bit-identical to the
       reference), and min/max are the run's first/last element. No
       scatter anywhere: XLA:CPU lowers segment ops to a serial
       per-row scatter that costs ~10x the sort at benchmark shapes.
-    - kernels/segment_sum family (``use_pallas`` — the TPU compile
-      target — plus float and 64-bit values, whose NaN propagation
-      and non-reorderable sums want the masked kernels). NaN
+    - segment (every other platform, and float and 64-bit values
+      everywhere): the masked kernels/segment_sum family — XLA
+      scatter, or the Pallas kernels under ``use_pallas``. On the TPU
+      the packed strategy's binary searches over every slot lower to
+      a ``while`` of gathers, far slower there than the scatter. NaN
       poisoning is baked into each shard's partial by
       ``masked_segment_reduce``, and jnp.min/max propagate it
       through the combine."""
@@ -335,6 +362,8 @@ def _partial_agg_fn(ndev: int, seg_shard: int, col_sig: tuple,
 
     mesh = _get_mesh(ndev)
     nseg = ndev * seg_shard
+    if strategies is None:
+        strategies = _agg_strategies(ndev, col_sig, use_pallas)
 
     def combine(x, mode: str):
         y = jax.lax.all_to_all(x, "shard", split_axis=0,
@@ -419,14 +448,13 @@ def _partial_agg_fn(ndev: int, seg_shard: int, col_sig: tuple,
         gid = gid_slab[0]
         outs = []
         i = 0
-        for dt_str, stats in col_sig:
-            dtype = np.dtype(dt_str)
+        for (dt_str, stats), strategy in zip(col_sig, strategies):
             vals = col_slabs[i][0]
             ok = col_slabs[i + 1][0]
             i += 2
-            if (dtype.kind in "iu" and dtype.itemsize <= 4
-                    and not use_pallas):
-                outs += reduce_packed(gid, vals, ok, stats, dtype)
+            if strategy == "packed":
+                outs += reduce_packed(gid, vals, ok, stats,
+                                      np.dtype(dt_str))
             else:
                 outs += reduce_kernels(gid, vals, ok, stats)
         return tuple(outs)
@@ -853,8 +881,10 @@ class ShardedBackend(JaxBackend):
                 col_slabs.append(slab(values, fill_value(values.dtype)))
                 col_slabs.append(slab(ok, False))
 
-        fn = _partial_agg_fn(ndev, seg_shard, tuple(col_sig),
-                             self.use_pallas, self.interpret)
+        col_sig = tuple(col_sig)
+        strategies = _agg_strategies(ndev, col_sig, self.use_pallas)
+        fn = _partial_agg_fn(ndev, seg_shard, col_sig, self.use_pallas,
+                             self.interpret, strategies)
         kernel_ctx = _NOOP_CTX
         if rec.enabled:
             # the exchange ships one lane per (shard, key slot) per
@@ -870,6 +900,7 @@ class ShardedBackend(JaxBackend):
             kernel_ctx = rec.span(
                 "kernel", op="sharded.partial_agg", ndev=ndev,
                 rows=n, slots=n_slots, segments=nseg,
+                strategy=list(strategies),
                 all_to_all_bytes=bytes_moved,
                 h2d_bytes=gid_slab.nbytes + sum(a.nbytes
                                                 for a in col_slabs),

@@ -194,6 +194,21 @@ def test_min_max_kernel_span():
     assert kernel.attrs["segments"] == 9
 
 
+def test_sharded_partial_agg_span_names_each_column_strategy():
+    """One strategy per value column, in the program's column order:
+    on a CPU mesh int32 takes the packed sort, float32 segment ops."""
+    r = np.random.default_rng(4)
+    t = Table({"k": r.integers(0, 50, 800).astype(np.int32),
+               "v": r.integers(0, 9, 800).astype(np.int32),
+               "w": r.normal(size=800).astype(np.float32)})
+    with obs.tracing() as rec:
+        t.group_by(["k"]).agg(("sum", "v", "s"), ("max", "w", "m"),
+                              ("min", "v", "lo"), backend="sharded")
+    (kernel,) = rec.spans("kernel")
+    assert kernel.attrs["op"] == "sharded.partial_agg"
+    assert kernel.attrs["strategy"] == ["packed", "segment"]
+
+
 def test_run_spans_under_node():
     c = _client()
     base = c.catalog.head("main")

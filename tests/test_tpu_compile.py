@@ -137,3 +137,26 @@ def test_sharded_partial_agg_compiles_for_4_chips(described_mesh):
         text = fn.lower(*args).compile().as_text()
     assert "all-to-all" in text
     assert "tpu_custom_call" in text
+
+
+def test_sharded_partial_agg_takes_segment_ops_on_4_chips(described_mesh):
+    """Without ``use_pallas`` the TPU mesh reduces each shard with XLA
+    segment ops: the all_to_all of the partials and no ``while`` — the
+    packed strategy's binary search over every slot is one."""
+    import re
+
+    from jax.sharding import NamedSharding, PartitionSpec as P
+
+    from repro.exec.sharded import _agg_strategies, _partial_agg_fn
+
+    sig = (("<i4", ("sum",)),)
+    assert _agg_strategies(NDEV, sig, False) == ("segment",)
+    chunk = N_ROWS // NDEV
+    fn = _partial_agg_fn(NDEV, N_SEGMENTS // NDEV, sig, False, False)
+    spec = NamedSharding(described_mesh, P("shard", None))
+    args = [jax.ShapeDtypeStruct((NDEV, chunk), dt, sharding=spec)
+            for dt in (jnp.int32, jnp.int32, jnp.bool_)]
+    with jax.enable_x64(True):
+        text = fn.lower(*args).compile().as_text()
+    assert "all-to-all" in text
+    assert not re.search(r"\bwhile\(", text)
